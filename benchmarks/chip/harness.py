@@ -1,0 +1,717 @@
+"""One run of one cell: build its store from the seed, warm up, drive the
+window, check what it produced, and reduce what was measured.
+
+Everything a cell is made of is found by name from ``BENCHMARK.json``:
+its configuration (``configs/<config>.json``), its traffic mix
+(``traffic/<traffic>.json``, read by ``traffic.py``) and its metrics
+(``metrics/<name>.py``, each a ``read(run)`` that returns a number or
+None; ``<base>.<part>`` may share ``metrics/<base>.py``).  Nothing here names a cell.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import json
+import os
+import shutil
+import tempfile
+import threading
+import time
+from concurrent.futures import ThreadPoolExecutor
+
+import numpy as np
+
+from . import check as C
+from . import reference as R
+from . import traffic as T
+from .recorder import Recorder
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+
+# query name -> the operator of its first stage (the cascades of the paper)
+STAGE0_OPS = {"A": "diff", "B": "motion"}
+# numbers compared, by traffic kind and whether a codec decode is read
+QUERY_CHECKS = ("missing", "convert_off_share", "score_err",
+                "item_mismatch", "uncompared")
+DECODE_CHECKS = ("decode_max_abs", "decode_off_share")
+INGEST_CHECKS = ("missing", "symbol_off_share", "raw_off_share")
+# after the window, how long answers still due may take to come
+LATE_S = 60.0
+
+
+# -- finding things by name -------------------------------------------------------
+
+def load_benchmark() -> dict:
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def load_config(name: str) -> dict:
+    with open(os.path.join(HERE, "configs", f"{name}.json")) as f:
+        return json.load(f)
+
+
+def metric_reader(name: str):
+    """``read`` of ``metrics/<name>.py``.  A quantity split by the
+    end-to-end metric it moves, ``<base>.<part>``, falls back to the one
+    reader ``metrics/<base>.py``."""
+    path = os.path.join(HERE, "metrics", f"{name}.py")
+    if not os.path.exists(path) and "." in name:
+        path = os.path.join(HERE, "metrics", f"{name.rsplit('.', 1)[0]}.py")
+    spec = importlib.util.spec_from_file_location(
+        f"benchmarks.chip.metrics.{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def cell_metrics(bench: dict, workload: str, kind: str) -> list[dict]:
+    """The ``end_to_end`` or ``per_layer`` entries this cell reports."""
+    out = []
+    for m in bench[kind]:
+        if "workloads" in m and workload not in m["workloads"]:
+            continue
+        out.append(m)
+    return out
+
+
+def require_chips(n: int) -> dict:
+    """The first ``n`` accelerator devices as JAX reports them; raises
+    where JAX finds no accelerator or fewer than ``n``."""
+    import jax
+    devs = jax.devices()
+    if devs[0].platform == "cpu":
+        raise RuntimeError(f"no accelerator: JAX sees {devs}")
+    if len(devs) < n:
+        raise RuntimeError(f"the cell asks for {n} chips, JAX sees "
+                           f"{len(devs)}")
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": n, "devices": devs[:n]}
+
+
+# -- the run's record ---------------------------------------------------------------
+
+@dataclasses.dataclass
+class Run:
+    """What the metric readers read."""
+    workload: str
+    config: dict
+    traffic: dict
+    device_kind: str
+    setup_s: float = 0.0
+    window_s: float = 0.0
+    records: list = dataclasses.field(default_factory=list)
+    spans: list = dataclasses.field(default_factory=list)
+    trace: dict | None = None
+    stored_bytes: int = 0
+    video_s: float = 0.0
+
+
+# -- the store ------------------------------------------------------------------------
+
+def program_objects(cfg: dict):
+    """The program's spec, derived configuration and server settings,
+    as the configuration file pins them."""
+    from repro.cluster.wire import config_from_wire
+    from repro.core.knobs import IngestSpec
+    spec = IngestSpec(**cfg["spec"])
+    dcfg = config_from_wire(cfg["derived_config"])
+    if _golden_node(dcfg).coding.speed != cfg["golden_speed"]:
+        raise ValueError("golden_speed disagrees with the derived config")
+    server = {k: (tuple(v) if isinstance(v, list) else v)
+              for k, v in cfg["server"].items()}
+    return spec, dcfg, server
+
+
+def make_pool(spec, cfg: dict, seed: int) -> list[np.ndarray]:
+    """The seed's pooled segments, rendered in parallel."""
+    from repro.analytics.scene import generate_segment
+    n, stream = cfg["pool_segments"], cfg["pool_stream"]
+    base = (int(seed) % (1 << 40)) * n
+    with ThreadPoolExecutor(n) as ex:
+        return list(ex.map(
+            lambda i: generate_segment(stream, base + i, spec)[0], range(n)))
+
+
+def cameras(cfg: dict, n: int | None = None) -> list[str]:
+    return [f"cam{i}" for i in range(cfg["cameras"] if n is None else n)]
+
+
+def build_archive(vs, dcfg, cfg: dict, pool: list) -> None:
+    """Every camera's segments, each a pooled segment encoded once per
+    storage format through the store and written under the camera's own
+    keys."""
+    from repro.core.knobs import FidelityOption
+    blobs, enc_s = {}, {}
+    for p, frames in enumerate(pool):
+        for sid, sf in dcfg.storage_formats().items():
+            t0 = time.perf_counter()
+            blobs[p, sid] = vs.encode_format(frames, FidelityOption(), sf)
+            enc_s[p, sid] = time.perf_counter() - t0
+    golden = _golden_id(dcfg)
+    for cam in cameras(cfg):
+        for seg in range(cfg["segments_per_camera"]):
+            p = seg % len(pool)
+            for sid in dcfg.storage_formats():
+                vs.put_segment(cam, seg, sid, blobs[p, sid],
+                               encode_s=enc_s[p, sid],
+                               count_segment=sid == golden)
+    vs.flush()
+
+
+def program_encode(spec, frames, sf) -> bytes:
+    """The program's blob of camera frames in storage format ``sf``."""
+    from repro.core.knobs import FidelityOption
+    from repro.videostore import VideoStore
+    with tempfile.TemporaryDirectory(prefix="chipbench-enc-") as d:
+        return VideoStore(d, spec).encode_format(frames, FidelityOption(), sf)
+
+
+def _golden_node(dcfg):
+    return [n for n in dcfg.nodes if n.golden][0]
+
+
+def _golden_id(dcfg) -> str:
+    return dcfg.node_id([n.golden for n in dcfg.nodes].index(True))
+
+
+def _fid(f) -> dict:
+    """A program ``FidelityOption`` as the reference's plain dict."""
+    return {"quality": f.quality, "crop": f.crop,
+            "resolution": f.resolution, "sampling": f.sampling}
+
+
+def stage0_formats(cfg: dict, dcfg, traffic: dict) -> dict:
+    """``{program cf: (op, frames per segment, cf fidelity, sf id, sf
+    fidelity)}`` of the first stages the mix runs."""
+    out = {}
+    for q, acc in traffic["mix"]:
+        op = STAGE0_OPS[q]
+        cf = dcfg.consumption_format(op, acc)
+        sf_id = dcfg.subscription(cf)
+        sf = dcfg.storage_formats()[sf_id]
+        cf_f, sf_f = _fid(cf), _fid(sf.fidelity)
+        n = R.resolve(cfg["spec"], cf_f)[0]
+        out[cf] = (op, n, cf_f, sf_id, sf_f)
+    return out
+
+
+# -- the window -----------------------------------------------------------------------
+
+class Tracing:
+    """``--trace 1``: the program's spans over the whole window, and a
+    device trace of a steady slice of it."""
+
+    def __init__(self, seconds: float, tmp: str):
+        self.seconds = seconds
+        self.dir = os.path.join(tmp, "profile")
+        self.spans: list[dict] = []
+        self._stop = threading.Event()
+        self._threads: list[threading.Thread] = []
+        self.slice: dict = {}
+
+    def start(self, t0: float) -> None:
+        from repro.obs import trace as obs
+        obs.TRACER.drain()
+        obs.enable(True)
+        self._threads = [threading.Thread(target=self._drain, daemon=True),
+                         threading.Thread(target=self._profile, args=(t0,),
+                                          daemon=True)]
+        for th in self._threads:
+            th.start()
+
+    def _drain(self) -> None:
+        from repro.obs import trace as obs
+        while not self._stop.wait(0.25):
+            self.spans += [s.to_wire() for s in obs.TRACER.drain()]
+
+    def _profile(self, t0: float) -> None:
+        import jax
+        from . import tracereduce as tr
+        start = t0 + 0.25 * self.seconds
+        length = min(10.0, 0.5 * self.seconds)
+        if self._stop.wait(max(0.0, start - time.perf_counter())):
+            return
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        with jax.profiler.TraceAnnotation(tr.ANCHOR):
+            anchor = time.perf_counter()
+        s0 = time.perf_counter()
+        self._stop.wait(length)
+        s1 = time.perf_counter()
+        jax.profiler.stop_trace()
+        self.slice = {"anchor_t": anchor, "s0": s0, "s1": s1}
+
+    def stop(self) -> None:
+        from repro.obs import trace as obs
+        self._stop.set()
+        for th in self._threads:
+            th.join()
+        obs.enable(False)
+        self.spans += [s.to_wire() for s in obs.TRACER.drain()]
+
+    def device_trace(self) -> dict | None:
+        from . import tracereduce as tr
+        if not self.slice:
+            return None
+        files = []
+        for d, _sub, names in os.walk(self.dir):
+            files += [os.path.join(d, n) for n in names
+                      if n.endswith(".xplane.pb")]
+        if not files:
+            return None
+        trace = tr.load_xplane(sorted(files)[-1])
+        if trace["anchor_ns"] is None:
+            return None
+        trace["anchor_t"] = self.slice["anchor_t"]
+        to_ns = lambda t: trace["anchor_ns"] + (t - trace["anchor_t"]) * 1e9
+        trace["t0_ns"] = to_ns(self.slice["s0"])
+        trace["t1_ns"] = to_ns(self.slice["s1"])
+        return trace
+
+
+def closed_loop(srv, traffic: dict, ranges: list, order, seconds: float
+                ) -> tuple[list[dict], float, float]:
+    """``clients`` callers until ``seconds`` have passed; each finishes
+    the request it holds.  Returns the records and the window (start,
+    end): from the first request to the last answer."""
+    from repro.obs import trace as obs
+    lock = threading.Lock()
+    nxt = [0]
+    records: list[dict] = []
+    t0 = time.perf_counter()
+    deadline = t0 + seconds
+
+    def client():
+        while time.perf_counter() < deadline:
+            with lock:
+                i = nxt[0]
+                nxt[0] += 1
+            q, cam, segs, acc = T.closed_request(traffic, ranges, order, i)
+            rec = {"i": i, "query": q, "stream": cam, "segments": segs,
+                   "accuracy": acc, "trace_id": obs.TRACER.new_id(),
+                   "due": time.perf_counter()}
+            try:
+                res = srv.submit(q, cam, segs, acc, block=True,
+                                 trace=(rec["trace_id"], 0)).result()
+                rec.update(ok=True, result=res)
+            except Exception as e:  # noqa: BLE001 - a failed request counts
+                rec.update(ok=False, error=f"{type(e).__name__}: {e}")
+            rec["end"] = time.perf_counter()
+            with lock:
+                records.append(rec)
+
+    threads = [threading.Thread(target=client)
+               for _ in range(traffic["clients"])]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    return records, t0, max([r["end"] for r in records] + [deadline])
+
+
+def open_loop(srv, arrivals: list, seconds: float
+              ) -> tuple[list[dict], float, float]:
+    """Requests sent at their due times whatever the server does; each is
+    timed from its due time.  Waits up to ``LATE_S`` past the window for
+    the answers."""
+    from repro.obs import trace as obs
+    from repro.serving.server import AdmissionError
+    records: list[dict] = []
+    done = threading.Semaphore(0)
+    t0 = time.perf_counter()
+    for off, (q, cam, segs, acc) in arrivals:
+        due = t0 + off
+        delay = due - time.perf_counter()
+        if delay > 0:
+            time.sleep(delay)
+        rec = {"query": q, "stream": cam, "segments": segs, "accuracy": acc,
+               "trace_id": obs.TRACER.new_id(), "due": due,
+               "sent": time.perf_counter()}
+        records.append(rec)
+        try:
+            ticket = srv.submit(q, cam, segs, acc, block=False,
+                                trace=(rec["trace_id"], 0))
+        except AdmissionError as e:
+            rec.update(ok=False, error=f"rejected: {e}",
+                       end=time.perf_counter())
+            done.release()
+            continue
+
+        def finished(fut, rec=rec):
+            rec["end"] = time.perf_counter()
+            if fut.exception() is None:
+                rec.update(ok=True, result=fut.result())
+            else:
+                rec.update(ok=False, error=repr(fut.exception()))
+            done.release()
+        ticket.future.add_done_callback(finished)
+    close = t0 + seconds
+    for _ in records:
+        if not done.acquire(timeout=max(0.0, close + LATE_S
+                                        - time.perf_counter())):
+            break
+    return records, t0, close
+
+
+def ingest_loop(vs, sched, traffic: dict, pool: list, spec, seconds: float
+                ) -> tuple[list[dict], float, float]:
+    """``cameras`` cameras each push pooled segments back to back; a
+    segment is acknowledged once its golden blob is written and the store
+    index flushed.  After ``seconds`` no camera starts another, and the
+    window closes when the queued derived formats are written too."""
+    lock = threading.Lock()
+    records: list[dict] = []
+    t0 = time.perf_counter()
+    deadline = t0 + seconds
+
+    def camera(c: int, cam: str):
+        k = 0
+        while time.perf_counter() < deadline:
+            rec = {"stream": cam, "seg": k, "content": (c + k) % len(pool),
+                   "due": time.perf_counter()}
+            try:
+                sched.ingest(cam, k, pool[rec["content"]])
+                vs.flush()
+                rec["ok"] = True
+            except Exception as e:  # noqa: BLE001 - a failed ingest counts
+                rec.update(ok=False, error=f"{type(e).__name__}: {e}")
+            rec["end"] = time.perf_counter()
+            rec["video_s"] = spec.segment_seconds
+            with lock:
+                records.append(rec)
+            k += 1
+
+    threads = [threading.Thread(target=camera, args=(c, cam))
+               for c, cam in enumerate(cameras({"cameras":
+                                                traffic["cameras"]}))]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    sched.stop(drain=True)
+    vs.flush()
+    return records, t0, time.perf_counter()
+
+
+# -- checks --------------------------------------------------------------------------
+
+def check_queries(vs, cfg: dict, dcfg, stage0: dict, rec: Recorder,
+                  records: list, pool_n: int, coded: bool) -> dict:
+    """Numbers compared in a query cell (see ``QUERY_CHECKS``).
+
+    Each layer is held to the reference of that layer given the input the
+    program's layer had: the sampled segments' decoded frames against the
+    float64 decode of their stored bytes, their converted frames against
+    the float64 conversion of those decoded frames, and every stage-0
+    operator call of the window against the float64 operator on the
+    converted frames it was given.  Every camera holds the same pooled
+    segments and the program is deterministic, so an operator call's
+    frames are those of its pooled segment's sample; a call whose frames
+    are not (by fingerprint) counts as a mismatch.  Every pooled segment
+    and stage-0 format that an answer covers has to have been compared
+    in some call, and every stage-0 call has to have shown its per-frame
+    outputs: what was not counts as ``uncompared``."""
+    from .recorder import fingerprint
+    spec = cfg["spec"]
+    t = C.Tally()
+    t.add("missing", sum(1 for r in records if not r.get("ok")))
+    sample_of = {seg % pool_n: (stream, seg) for stream, seg in rec.sample}
+
+    def held(p, cf):
+        """(fingerprint, reference output, items, positions) of pooled
+        segment ``p``'s conversion to ``cf``, or None if unseen."""
+        op, _n, cf_f, sf_id, sf_f = stage0[cf]
+        key = sample_of.get(p)
+        conv = rec.converts.get(key + (cf,)) if key else None
+        rows = R.stored_rows(spec, sf_f, cf_f)
+        dec = next((frames[np.searchsorted(want, rows)]
+                    for want, frames in rec.decodes.get(key + (sf_id,), ())
+                    if np.isin(rows, want).all()), None) if key else None
+        if conv is None or dec is None:
+            return None
+        pos = R.sample_positions(spec["fps"] * spec["segment_seconds"],
+                                 cf_f["sampling"])
+        raw, items = R.stage0(op, conv, pos, cf_f["crop"], spec)
+        return (fingerprint(conv), raw, items, pos, conv,
+                R.convert_frames(dec, spec, sf_f, cf_f))
+
+    with ThreadPoolExecutor(4) as ex:
+        keys = [(p, cf) for p in range(pool_n) for cf in stage0]
+        ref = dict(zip(keys, ex.map(lambda k: held(*k), keys)))
+        decoded = dict(zip(rec.decodes, ex.map(
+            lambda k: R.decode_blob(vs.backend.get(
+                f"{k[0]}:{k[2]}:{k[1]:06d}")), list(rec.decodes))))
+    for key, kept in rec.decodes.items():
+        for want, frames in kept:
+            t.frames("decode", frames, decoded[key][want])
+    for r in ref.values():
+        if r is not None:
+            t.frames("convert", r[4], r[5])
+    compared = set()   # (pooled segment, cf) of the blocks compared
+    for cf, fp, raw, items in rec.blocks:
+        where = rec.where.get(fp)
+        r = ref.get((where[1] % pool_n, cf)) if where else None
+        if r is None or r[0] != fp:
+            t.add("item_mismatch")   # frames the check cannot place
+            continue
+        C.compare_stage0(t, stage0[cf][0], raw, items, r[1], r[2], r[3],
+                         spec)
+        compared.add((where[1] % pool_n, cf))
+    answered = set()
+    # an answer whose first stage the reference finds empty must be empty
+    for r in records:
+        if not r.get("ok"):
+            continue
+        cf = dcfg.consumption_format(STAGE0_OPS[r["query"]], r["accuracy"])
+        answered |= {(s % pool_n, cf) for s in r["segments"]}
+        refs = [ref.get((s % pool_n, cf)) for s in r["segments"]]
+        if any(x is None for x in refs):
+            t.add("item_mismatch")
+        elif all(not x[2] for x in refs) and r["result"].items:
+            t.add("item_mismatch")
+    t.add("uncompared", rec.unread + len(answered - compared))
+    names = list(QUERY_CHECKS) + (list(DECODE_CHECKS) if coded else [])
+    return t.numbers(names)
+
+
+def check_ingest(root: str, spec, dcfg, cfg: dict, records: list,
+                 pool: list) -> dict:
+    """Numbers compared in an ingest cell: every acknowledged segment read
+    back from the reopened store, its golden symbols against an exact
+    encoding of the camera's frames, its derived formats against the
+    reference conversion of its golden blob's decode."""
+    from repro.videostore import VideoStore
+    vs = VideoStore(os.path.join(root, "store"), spec, readonly=True)
+    sp = cfg["spec"]
+    golden, node = _golden_id(dcfg), _golden_node(dcfg)
+    g_f = _fid(node.fidelity)
+    derived = {sid: _fid(sf.fidelity)
+               for sid, sf in dcfg.storage_formats().items() if sid != golden}
+    t = C.Tally()
+    t.add("missing", sum(1 for r in records if not r.get("ok")))
+    acked = [r for r in records if r.get("ok")]
+
+    def ref_content(c):
+        full = None
+        blob = next((b for b in _read(vs, acked, golden, c) if b), None)
+        if blob is not None:
+            full = R.decode_blob(blob)
+        sym = R.encode_symbols(pool[c], node.fidelity.quant_scale,
+                               node.coding.keyframe)
+        conv = ({sid: R.convert_frames(full[R.stored_rows(sp, g_f, f)], sp,
+                                       g_f, f)
+                 for sid, f in derived.items()} if full is not None else {})
+        return c, (blob, sym, conv)
+    with ThreadPoolExecutor(4) as ex:
+        ref = dict(ex.map(ref_content, sorted({r["content"] for r in acked})))
+    seen: dict[tuple, tuple] = {}   # identical bytes compare alike
+    for r in acked:
+        key = f"{r['seg']:06d}"
+        try:
+            gblob = vs.backend.get(f"{r['stream']}:{golden}:{key}")
+        except KeyError:
+            t.add("missing")
+            continue
+        blob0, sym, conv = ref[r["content"]]
+        if ("symbol", gblob) not in seen:
+            seen["symbol", gblob] = C.frame_counts(R.blob_symbols(gblob), sym)
+        t.counts("symbol", seen["symbol", gblob])
+        if gblob != blob0:  # a second encoding of the same frames
+            full = R.decode_blob(gblob)
+            conv = {sid: R.convert_frames(full[R.stored_rows(sp, g_f, f)],
+                                          sp, g_f, f)
+                    for sid, f in derived.items()}
+        for sid in derived:
+            try:
+                dblob = vs.backend.get(f"{r['stream']}:{sid}:{key}")
+            except KeyError:
+                t.add("missing")
+                continue
+            if (sid, gblob, dblob) not in seen:
+                seen[sid, gblob, dblob] = C.frame_counts(
+                    R.decode_blob(dblob), conv[sid])
+            t.counts("raw", seen[sid, gblob, dblob])
+    return t.numbers(list(INGEST_CHECKS))
+
+
+def _read(vs, acked: list, sf_id: str, content: int):
+    """The stored blobs of ``sf_id`` of acknowledged segments holding
+    pooled segment ``content`` (None where absent)."""
+    for r in acked:
+        if r["content"] == content:
+            try:
+                yield vs.backend.get(f"{r['stream']}:{sf_id}:{r['seg']:06d}")
+            except KeyError:
+                yield None
+
+
+# -- one run ---------------------------------------------------------------------------
+
+def run_cell(workload: str, seed: int, seconds: float, trace: bool,
+             t_start: float, log=print) -> tuple[dict, dict]:
+    """Run one cell once.  Returns the result line (without ``checks``)
+    and the table of numbers compared."""
+    bench = load_benchmark()
+    cell = {w["name"]: w for w in bench["workloads"]}[workload]
+    device = require_chips(cell["chips"])
+    cfg = load_config(cell["config"])
+    traffic = T.load(cell["traffic"])
+    spec, dcfg, server_kw = program_objects(cfg)
+    run = Run(workload, cfg, traffic, device["kind"])
+    tmp = tempfile.mkdtemp(prefix="chipbench-")
+    try:
+        pool = make_pool(spec, cfg, seed)
+        log(f"pool: {len(pool)} segments of {pool[0].shape} "
+            f"({time.perf_counter() - t_start:.1f}s)")
+        if traffic["kind"] == "ingest":
+            out = _ingest_cell(run, spec, dcfg, cfg, traffic, pool, seconds,
+                               trace, tmp, t_start, device, log)
+        else:
+            out = _query_cell(run, spec, dcfg, cfg, server_kw, traffic, pool,
+                              seed, seconds, trace, tmp, t_start, device,
+                              log)
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+    return out
+
+
+def _memory_peak(device: dict) -> int:
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+             for d in device["devices"]]
+    return int(max(peaks))
+
+
+def _query_cell(run, spec, dcfg, cfg, server_kw, traffic, pool, seed,
+                seconds, trace, tmp, t_start, device, log):
+    from repro.serving import VStoreServer
+    from repro.videostore import VideoStore
+    vs = VideoStore(os.path.join(tmp, "store"), spec)
+    vs.set_formats(dcfg.storage_formats())
+    build_archive(vs, dcfg, cfg, pool)
+    log(f"archive: {cfg['cameras']} cameras x {cfg['segments_per_camera']} "
+        f"segments, {vs.storage_bytes()} bytes "
+        f"({time.perf_counter() - t_start:.1f}s)")
+    ranges = T.all_ranges(traffic, cameras(cfg), cfg["segments_per_camera"])
+    content = lambda cam, seg: seg % len(pool)
+    warm = T.warm_requests(traffic, ranges, content)
+    stage0 = stage0_formats(cfg, dcfg, traffic)
+    rng = np.random.default_rng([int(seed) % (1 << 63), 3])
+    # one sampled (camera, segment) of every pooled segment, all of them
+    # read within the first cycle of the window's ranges
+    sample = set()
+    for p in range(len(pool)):
+        keys = [(cam, s) for cam, segs in ranges for s in segs
+                if content(cam, s) == p]
+        if keys:
+            sample.add(keys[int(rng.integers(len(keys)))])
+    rec = Recorder({cf: v[:2] for cf, v in stage0.items()}, sample,
+                   max(1, spec.fps // 2))
+    if traffic["loop"] == "closed":
+        with VStoreServer(vs, dcfg, **server_kw) as w:
+            w.run_batch(warm)
+        srv = VStoreServer(vs, dcfg, **server_kw)
+        rec.install(vs, srv)
+    else:
+        srv = VStoreServer(vs, dcfg, **server_kw)
+        rec.install(vs, srv)
+        for q in warm:  # one at a time: the cache fills as in service
+            srv.submit(*q, block=True).result()
+    log(f"warm: {len(warm)} requests ({time.perf_counter() - t_start:.1f}s)")
+    tracing = Tracing(seconds, tmp) if trace else None
+    run.setup_s = time.perf_counter() - t_start
+    try:
+        if tracing:
+            tracing.start(time.perf_counter())
+        if traffic["loop"] == "closed":
+            records, w0, w1 = closed_loop(
+                srv, traffic, ranges, T.closed_order(ranges, seed),
+                seconds)
+        else:
+            records, w0, w1 = open_loop(
+                srv, T.open_arrivals(traffic, ranges, seed, seconds), seconds)
+    finally:
+        if tracing:
+            tracing.stop()
+        mem = _memory_peak(device)
+        srv.close()
+        rec.uninstall()
+    run.records, run.window_s = records, w1 - w0
+    log(f"window: {len(records)} requests in {run.window_s:.3f}s")
+    coded = any(not dcfg.storage_formats()[v[3]].coding.bypass
+                for v in stage0.values())
+    t_check = time.perf_counter()
+    numbers = check_queries(vs, cfg, dcfg, stage0, rec, records, len(pool),
+                            coded)
+    log(f"check: {time.perf_counter() - t_check:.1f}s")
+    return _finish(run, tracing, numbers, records, device, mem)
+
+
+def _ingest_cell(run, spec, dcfg, cfg, traffic, pool, seconds, trace, tmp,
+                 t_start, device, log):
+    from repro.ingest import IngestScheduler
+    from repro.videostore import VideoStore
+    root = os.path.join(tmp, "cell")
+    vs = VideoStore(os.path.join(root, "store"), spec)
+    vs.set_formats(dcfg.storage_formats())
+    sched = IngestScheduler(vs, dcfg, budget_x=traffic.get("budget_x"))
+    sched.ingest("warm", 0, pool[0])  # compiles the encode and transcode
+    sched.drain()
+    vs.flush()
+    sched.start()
+    log(f"warm: one segment ({time.perf_counter() - t_start:.1f}s)")
+    tracing = Tracing(seconds, tmp) if trace else None
+    run.setup_s = time.perf_counter() - t_start
+    try:
+        if tracing:
+            tracing.start(time.perf_counter())
+        records, w0, w1 = ingest_loop(vs, sched, traffic, pool, spec,
+                                      seconds)
+    finally:
+        if tracing:
+            tracing.stop()
+        sched.stop()
+        mem = _memory_peak(device)
+    run.records, run.window_s = records, w1 - w0
+    streams = {r["stream"] for r in records}
+    run.stored_bytes = sum(vs.ingest_stats[s].stored_bytes for s in streams)
+    run.video_s = sum(vs.ingest_stats[s].segments * spec.segment_seconds
+                      for s in streams)
+    log(f"window: {len(records)} segments in {run.window_s:.3f}s")
+    t_check = time.perf_counter()
+    numbers = check_ingest(root, spec, dcfg, cfg, records, pool)
+    log(f"check: {time.perf_counter() - t_check:.1f}s")
+    return _finish(run, tracing, numbers, records, device, mem)
+
+
+def _finish(run, tracing, numbers, records, device, mem):
+    bench = load_benchmark()
+    if tracing:
+        run.spans = tracing.spans
+        run.trace = tracing.device_trace()
+    kind = "per_layer" if tracing else "end_to_end"
+    metrics = {}
+    for m in cell_metrics(bench, run.workload, kind):
+        v = metric_reader(m["name"])(run)
+        if v is not None:
+            metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
+    dev = {"platform": device["platform"], "kind": device["kind"],
+           "count": device["count"], "memory_peak_bytes": mem}
+    line = {"correct": None,
+            "attempted": len(records),
+            "failed": sum(1 for r in records if not r.get("ok")),
+            "metrics": metrics, "device": dev}
+    if tracing:
+        from . import tracereduce as tr
+        if run.trace is not None:
+            dev["busy_s"] = tr.busy_s(run.trace)
+            dev["window_s"] = (run.trace["t1_ns"] - run.trace["t0_ns"]) / 1e9
+            line["breakdown"] = {
+                "device_ops": tr.top_modules(run.trace),
+                "idle_gaps": tr.idle_gaps(run.trace, run.spans)}
+    ok, table = C.verdict(numbers)
+    line["correct"] = ok
+    return line, table
