@@ -1,0 +1,8 @@
+"""Fidelity convert: the on-CPU share of the ``convert`` spans (their
+threads' CPU time over their wall time)."""
+
+from benchmarks.chip import hostlayers as hl
+
+
+def read(run):
+    return hl.cpu_pct(run.spans, "convert")

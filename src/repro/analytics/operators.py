@@ -102,12 +102,12 @@ class Diff(Operator):
     threshold = 0.012  # mean-abs-diff rate per original-timeline frame
 
     def detect(self, frames_u8, cf, spec, positions=None):
-        x = jnp.asarray(frames_u8, jnp.float32) / 255.0
+        x = T.to_device(frames_u8, jnp.float32) / 255.0
         if x.shape[0] < 2:
             return set()
         pos = _positions(cf, spec) if positions is None else positions
         gaps = np.maximum(1, np.diff(pos))
-        scores = np.asarray(_diff_scores(x)) / gaps  # per-frame change rate
+        scores = T.to_host(_diff_scores(x)) / gaps  # per-frame change rate
         return {("evt", _bucket(pos[i + 1], spec))
                 for i in np.nonzero(scores > self.threshold)[0]}
 
@@ -134,11 +134,11 @@ class Motion(Operator):
 
     def detect(self, frames_u8, cf, spec, positions=None):
         ty, tx = self.grid
-        x = jnp.asarray(frames_u8, jnp.float32) / 255.0
+        x = T.to_device(frames_u8, jnp.float32) / 255.0
         n, h, w = x.shape
         if h < ty or w < tx:
             return set()
-        tiles = np.asarray(_motion_tiles(x, ty, tx))
+        tiles = T.to_host(_motion_tiles(x, ty, tx))
         # excess over the frame's median tile: robust to the uniform noise /
         # smoothing floor (quality knob), sensitive to car-specific edges
         med = np.median(tiles.reshape(n, -1), axis=1)[:, None, None]
@@ -184,11 +184,11 @@ class SNN(Operator):
 
     def detect(self, frames_u8, cf, spec, positions=None):
         gy, gx = self.grid
-        x = jnp.asarray(frames_u8, jnp.float32) / 255.0
+        x = T.to_device(frames_u8, jnp.float32) / 255.0
         n, h, w = x.shape
         if h < gy + 5 or w < gx + 5:
             return set()
-        cells = np.asarray(_snn_scores(x, gy, gx))
+        cells = T.to_host(_snn_scores(x, gy, gx))
         pos = _positions(cf, spec) if positions is None else positions
         items = set()
         for t, iy, ix in zip(*np.nonzero(cells > self.threshold)):
@@ -231,13 +231,13 @@ class NN(Operator):
     qgrid = 8
 
     def detect(self, frames_u8, cf, spec, positions=None):
-        x = jnp.asarray(frames_u8, jnp.float32) / 255.0
+        x = T.to_device(frames_u8, jnp.float32) / 255.0
         n, h, w = x.shape
         pos = _positions(cf, spec) if positions is None else positions
         items = set()
         for si, s in enumerate(self.scales):
             h2, w2 = max(14, int(h * s)), max(14, int(w * s))
-            sc = np.asarray(_nn_scale_scores(x, h2, w2))
+            sc = T.to_host(_nn_scale_scores(x, h2, w2))
             for t, iy, ix in zip(*np.nonzero(sc > self.threshold)):
                 cy, cx = _to_norm(iy + 6, ix + 6, h2, w2, cf.crop)
                 q = self.qgrid
@@ -268,10 +268,10 @@ class License(Operator):
     qgrid = 12
 
     def score_map(self, frames_u8) -> np.ndarray:
-        x = jnp.asarray(frames_u8, jnp.float32) / 255.0
+        x = T.to_device(frames_u8, jnp.float32) / 255.0
         if x.shape[1] < 7 or x.shape[2] < 13:
             return np.zeros((x.shape[0], 1, 1), np.float32)
-        return np.asarray(_license_scores(x))
+        return T.to_host(_license_scores(x))
 
     def detect(self, frames_u8, cf, spec, positions=None):
         sc = self.score_map(frames_u8)

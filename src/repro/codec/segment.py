@@ -224,33 +224,36 @@ def encode_segment(frames_u8: np.ndarray, *, quant_scale: float,
     k = keyframe_interval
     backend, interp = T.dct_backend(), T.dct_interpret()
     parts = []
-    for start in range(0, n, k):
-        kc = min(k, n - start)
-        chunk = jnp.asarray(_pad_tail(frames[start:start + kc], _k_eff(k, n)),
-                            jnp.float32)
-        sym = _encode_chunk(chunk, jnp.float32(quant_scale),
-                            backend=backend, interpret=interp)
-        parts.append(np.asarray(sym)[:kc])
+    with _span("codec.transform", chunks=-(-n // k), frames=n):
+        for start in range(0, n, k):
+            kc = min(k, n - start)
+            chunk = T.to_device(_pad_tail(frames[start:start + kc],
+                                          _k_eff(k, n)), jnp.float32)
+            sym = _encode_chunk(chunk, jnp.float32(quant_scale),
+                                backend=backend, interpret=interp)
+            parts.append(T.to_host(sym)[:kc])
     header = {
         "magic": _MAGIC, "raw": False, "n": n, "h": h, "w": w,
         "k": k, "qs": float(quant_scale), "lvl": zstd_level,
     }
-    if version == 1:
-        coder, comp = _compress(b"".join(p.tobytes() for p in parts),
-                                zstd_level)
-        header["ec"] = coder
-        payload = comp
-    else:
-        spans, blobs = [], []
-        coder = None
-        for p in parts:
-            coder, comp = _compress(p.tobytes(), zstd_level)
-            spans.append(len(comp))
-            blobs.append(comp)
-        header["v"] = 2
-        header["ec"] = coder or _compress(b"", zstd_level)[0]
-        header["spans"] = spans
-        payload = b"".join(blobs)
+    with _span("codec.compress", chunks=len(parts)) as csp:
+        if version == 1:
+            coder, comp = _compress(b"".join(p.tobytes() for p in parts),
+                                    zstd_level)
+            header["ec"] = coder
+            payload = comp
+        else:
+            spans, blobs = [], []
+            coder = None
+            for p in parts:
+                coder, comp = _compress(p.tobytes(), zstd_level)
+                spans.append(len(comp))
+                blobs.append(comp)
+            header["v"] = 2
+            header["ec"] = coder or _compress(b"", zstd_level)[0]
+            header["spans"] = spans
+            payload = b"".join(blobs)
+        csp.set(bytes=len(payload))
     packed = msgpack.packb(header)
     return struct.pack("<I", len(packed)) + packed + payload
 
@@ -366,8 +369,8 @@ def _decode_raw(header: dict, payload: bytes, hlen: int,
 
 def _run_decode(sym_padded: np.ndarray, header: dict) -> np.ndarray:
     """One ``_decode_chunks`` dispatch on the resolved transform backend."""
-    return np.asarray(_decode_chunks(
-        jnp.asarray(sym_padded), jnp.float32(header["qs"]),
+    return T.to_host(_decode_chunks(
+        T.to_device(sym_padded), jnp.float32(header["qs"]),
         backend=T.dct_backend(), interpret=T.dct_interpret()))
 
 

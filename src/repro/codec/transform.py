@@ -16,7 +16,40 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import COMPILES
+from ..obs.trace import count as _count
+
+# every data-path module that runs JAX programs imports this one, so the
+# process's compile counter listens before any of them compiles
+COMPILES.install()
+
 BLOCK = 8
+
+
+# ---------------------------------------------------------------------------
+# Host <-> device crossings, counted in the tracer's per-thread tallies
+# ---------------------------------------------------------------------------
+
+def to_device(x, dtype=None) -> jax.Array:
+    """``jnp.asarray(x, dtype)``, counting the bytes a host array sends up
+    as ``h2d_bytes``: numpy input is cast on the host first (to ``dtype``,
+    canonicalized), so that buffer is what crosses.  An array already on
+    the device crosses nothing."""
+    if isinstance(x, np.ndarray):
+        dt = jax.dtypes.canonicalize_dtype(x.dtype if dtype is None
+                                           else dtype)
+        _count("h2d_bytes", x.size * dt.itemsize)
+    return jnp.asarray(x, dtype)
+
+
+def to_host(x) -> np.ndarray:
+    """``np.asarray(x)``, counting a device array's bytes as
+    ``d2h_bytes``."""
+    out = np.asarray(x)
+    if isinstance(x, jax.Array):
+        _count("d2h_bytes", out.nbytes)
+    return out
+
 
 # ---------------------------------------------------------------------------
 # DCT backend selection: which implementation the codec's hot transforms
@@ -265,7 +298,7 @@ def spatial_convert(frames, f_from, f_to, spec):
     ``f_from``'s grid to ``f_to``'s.  Returns uint8."""
     _, h_to, w_to = spec.resolve(f_to)
     rel_crop = f_to.crop / f_from.crop
-    x = center_crop(jnp.asarray(frames, jnp.float32), min(1.0, rel_crop))
+    x = center_crop(to_device(frames, jnp.float32), min(1.0, rel_crop))
     x = resize(x, h_to, w_to)
     return jnp.clip(jnp.round(x), 0, 255).astype(jnp.uint8)
 
@@ -281,7 +314,7 @@ def convert_fidelity(frames_u8, f_from, f_to, spec):
     if not f_from.richer_eq(f_to):
         raise ValueError(f"fidelity {f_from.name()} cannot serve {f_to.name()}")
     n_from, _, _ = spec.resolve(f_from)
-    frames = jnp.asarray(frames_u8)
+    frames = to_device(frames_u8)
     if frames.shape[0] != n_from:
         raise ValueError(f"segment has {frames.shape[0]} frames, spec says {n_from}")
     frames = frames[temporal_indices(f_from, f_to, spec)]

@@ -13,12 +13,12 @@ a submodule import: ``from repro.obs import telemetry``.
 
 from .drift import DriftDetector, merge_reports, retrieval_expectations
 from .metrics import DEFAULT_BOUNDS, Histogram, MetricsRegistry
-from .trace import (TRACER, Span, Tracer, chrome_trace_events, enable,
-                    export_trace, span)
+from .trace import (COMPILES, TRACER, Span, Tracer, chrome_trace_events,
+                    count, enable, export_trace, span)
 
 __all__ = [
-    "TRACER", "Span", "Tracer", "chrome_trace_events", "enable",
-    "export_trace", "span",
+    "COMPILES", "TRACER", "Span", "Tracer", "chrome_trace_events", "count",
+    "enable", "export_trace", "span",
     "DEFAULT_BOUNDS", "Histogram", "MetricsRegistry",
     "DriftDetector", "merge_reports", "retrieval_expectations",
 ]

@@ -14,6 +14,16 @@ Disabled cost is one attribute read plus a shared no-op context manager:
 instrumentation in hot paths is free enough to keep everywhere (the
 ``obs_overhead`` bench gates this, < 3% on the full query path).
 
+Spans also see inside a layer.  Each thread keeps running tallies:
+``count("h2d_bytes", n)`` / ``count("d2h_bytes", n)`` at every host<->device
+crossing add the bytes that crossed, and the thread's CPU clock
+(``time.thread_time``) is read at span enter and exit.  A span records, as
+attributes, how much of each moved while it was open (``cpu`` seconds,
+``h2d_bytes``, ``d2h_bytes``), so a parent's figures include its children's
+on the same thread.  ``COMPILES`` counts the XLA programs the process
+compiles or loads from JAX's persistent cache and, while tracing is on,
+records each as a ``jax.compile`` span.
+
 Cross-process timelines: span/trace ids embed a per-process random salt,
 so ids minted on different shard workers never collide; workers ship
 finished spans as plain dicts next to their ``QueryResult`` wire forms and
@@ -27,10 +37,13 @@ from __future__ import annotations
 
 import itertools
 import json
+import logging
 import os
 import threading
 import time
 from collections import deque
+
+_log = logging.getLogger(__name__)
 
 
 class Span:
@@ -93,7 +106,7 @@ class _SpanCM:
     attributes discovered mid-span (hit kind, bytes touched ...)."""
 
     __slots__ = ("_tr", "name", "attrs", "trace_id", "span_id",
-                 "parent_id", "_t0")
+                 "parent_id", "_t0", "_c0", "_n0")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tr = tracer
@@ -120,22 +133,36 @@ class _SpanCM:
                 self.trace_id, self.parent_id = tr.new_id(), 0
         self.span_id = tr.new_id()
         stack.append((self.trace_id, self.span_id))
+        tally = getattr(tls, "tally", None)
+        self._n0 = tally.copy() if tally else None
+        self._c0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        cpu = time.thread_time() - self._c0
         tr = self._tr
+        tls = tr._tls
         # pop up to and including our own entry: an exception between an
         # explicitly paired enter/exit deeper in may have orphaned inner
         # entries, and a reused pool thread must not inherit them
-        stack = tr._tls.stack
+        stack = tls.stack
         while stack:
             if stack.pop()[1] == self.span_id:
                 break
+        attrs = self.attrs
+        if cpu:
+            attrs["cpu"] = cpu
+        tally = getattr(tls, "tally", None)
+        if tally:
+            n0 = self._n0 or {}
+            for k, v in tally.items():
+                if v != n0.get(k, 0):
+                    attrs[k] = v - n0.get(k, 0)
         tr.record(Span(self.name, self.trace_id, self.span_id,
                        self.parent_id, self._t0, t1 - self._t0, tr.pid,
-                       threading.get_ident(), self.attrs))
+                       tr.native_id(), attrs))
         return False
 
 
@@ -165,7 +192,8 @@ class _Activate:
 
 class Tracer:
     """Per-process span collector.  All public methods are thread-safe;
-    ``enabled`` is a plain attribute read on the hot path."""
+    ``enabled`` is a plain attribute read on the hot path.  ``dropped``
+    counts the spans a full ring evicted before anyone drained them."""
 
     def __init__(self, capacity: int = 16384, pid: int | None = None):
         self.enabled = False
@@ -173,11 +201,26 @@ class Tracer:
         self.pid = os.getpid() if pid is None else pid
         self._mu = threading.Lock()
         self._spans: deque[Span] = deque(maxlen=self.capacity)  # guarded-by: _mu
+        self.dropped = 0  # guarded-by: _mu
+        self._dropped_on = 0  # ``dropped`` when tracing was last enabled
         self._tls = threading.local()
         # ids unique across processes without coordination: a random
         # 32-bit per-process salt above a monotone counter
         self._salt = int.from_bytes(os.urandom(4), "big") | 1
         self._ids = itertools.count(1)
+
+    def enable(self, on: bool = True) -> None:
+        """Turn tracing on or off.  Turning it off logs a warning (on
+        stderr by default) with the number of spans the full ring evicted
+        while it was on, if any."""
+        with self._mu:
+            if on and not self.enabled:
+                self._dropped_on = self.dropped
+            lost = self.dropped - self._dropped_on
+        if not on and self.enabled and lost:
+            _log.warning("tracing: the full ring (%d spans) evicted %d spans "
+                         "before they were drained", self.capacity, lost)
+        self.enabled = on
 
     # -- id / context --------------------------------------------------------
     def new_id(self) -> int:
@@ -193,6 +236,28 @@ class Tracer:
 
     def activate(self, trace_id: int, parent_id: int) -> _Activate:
         return _Activate(self, int(trace_id), int(parent_id))
+
+    def native_id(self) -> int:
+        """``threading.get_native_id()``, read once a thread: it is a
+        system call, which costs microseconds on some hosts."""
+        tls = self._tls
+        tid = getattr(tls, "tid", None)
+        if tid is None:
+            tid = tls.tid = threading.get_native_id()
+        return tid
+
+    # -- per-thread tallies --------------------------------------------------
+    def count(self, name: str, n: int) -> None:
+        """Add ``n`` to this thread's running tally ``name`` (only while
+        tracing is enabled): every span open on the thread records the
+        change as its attribute ``name``."""
+        if not self.enabled:
+            return
+        tls = self._tls
+        tally = getattr(tls, "tally", None)
+        if tally is None:
+            tally = tls.tally = {}
+        tally[name] = tally.get(name, 0) + n
 
     # -- span creation -------------------------------------------------------
     def span(self, name: str, **attrs):
@@ -222,11 +287,13 @@ class Tracer:
         """Record a ``start_span`` handle."""
         self.record(Span(cm.name, cm.trace_id, cm.span_id, cm.parent_id,
                          cm._t0, time.perf_counter() - cm._t0, self.pid,
-                         threading.get_ident(), cm.attrs))
+                         self.native_id(), cm.attrs))
 
     # -- ring buffer ---------------------------------------------------------
     def record(self, span: Span) -> None:
         with self._mu:
+            if len(self._spans) == self.capacity:
+                self.dropped += 1
             self._spans.append(span)
 
     def spans(self) -> list[Span]:
@@ -268,6 +335,8 @@ class Tracer:
             if pid is not None:
                 sp.pid = pid
         with self._mu:
+            self.dropped += max(0, len(self._spans) + len(spans)
+                                - self.capacity)
             self._spans.extend(spans)
         return len(spans)
 
@@ -283,8 +352,101 @@ def span(name: str, **attrs):
     return _SpanCM(TRACER, name, attrs)
 
 
+def count(name: str, n: int) -> None:
+    """``TRACER.count``; disabled cost is one attribute read."""
+    if TRACER.enabled:
+        TRACER.count(name, n)
+
+
 def enable(on: bool = True) -> None:
-    TRACER.enabled = on
+    TRACER.enable(on)
+
+
+# -- compiles -------------------------------------------------------------------
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class CompileCounter:
+    """Process-wide count of the XLA programs JAX compiles or loads from
+    its persistent compilation cache, and the seconds they take.
+
+    Always on once ``install``ed (compiles are rare): JAX reports each
+    program's compile-or-load as one ``COMPILE_EVENT`` time span, and a
+    cache load also fires ``CACHE_HIT_EVENT`` on the same thread inside it.
+    While ``tracer`` is enabled each is also recorded as a ``jax.compile``
+    span (attributes ``fun`` and ``cache``: ``hit`` or ``miss``), parented
+    under the span open on the compiling thread, on ``perf_counter``'s
+    clock."""
+
+    def __init__(self, tracer: Tracer):
+        self._tr = tracer
+        self._mu = threading.Lock()
+        self._tls = threading.local()
+        self._installed = False
+        self.compiles = 0     # guarded-by: _mu
+        self.cache_loads = 0  # guarded-by: _mu
+        self.seconds = 0.0    # guarded-by: _mu
+        # (end on perf_counter, seconds, fun, cache hit) of the newest
+        self._log: deque[tuple] = deque(maxlen=4096)  # guarded-by: _mu
+
+    def install(self) -> None:
+        """Register with ``jax.monitoring`` (once; imports JAX)."""
+        with self._mu:
+            if self._installed:
+                return
+            self._installed = True
+        from jax import monitoring
+        monitoring.register_event_listener(self._on_event)
+        monitoring.register_event_time_span_listener(self._on_span)
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == CACHE_HIT_EVENT:
+            self._tls.hit = True
+
+    def _on_span(self, event: str, start: float, end: float, **kw) -> None:
+        if event != COMPILE_EVENT:
+            return
+        hit = getattr(self._tls, "hit", False)
+        self._tls.hit = False
+        dur = end - start
+        t0 = start + (time.perf_counter() - time.time())
+        fun = str(kw.get("fun_name", "?"))
+        with self._mu:
+            if hit:
+                self.cache_loads += 1
+            else:
+                self.compiles += 1
+            self.seconds += dur
+            self._log.append((t0 + dur, dur, fun, hit))
+        tr = self._tr
+        if tr.enabled:
+            trace_id, parent = tr.current()
+            tr.record(Span("jax.compile", trace_id or tr.new_id(),
+                           tr.new_id(), parent, t0, dur, tr.pid,
+                           tr.native_id(),
+                           {"fun": fun, "cache": "hit" if hit else "miss"}))
+
+    def snapshot(self) -> dict:
+        """``compiles``, ``cache_loads`` and ``seconds`` so far."""
+        with self._mu:
+            return {"compiles": self.compiles,
+                    "cache_loads": self.cache_loads,
+                    "seconds": self.seconds}
+
+    def events(self) -> list[dict]:
+        """The newest compiles and loads, oldest first: ``t1`` (end, on
+        ``perf_counter``), ``s`` (seconds), ``fun`` and ``cache``."""
+        with self._mu:
+            log = list(self._log)
+        return [{"t1": t1, "s": s, "fun": fun, "cache": "hit" if hit
+                 else "miss"} for t1, s, fun, hit in log]
+
+
+#: the process's compile counter; the first program module that imports
+#: JAX installs it
+COMPILES = CompileCounter(TRACER)
 
 
 # -- Chrome trace-event export ------------------------------------------------

@@ -200,8 +200,10 @@ class VideoStore:
         implementation shared by blocking ingest, the background scheduler
         and fallback-chain reconstruction, so all three produce identical
         bytes from identical input."""
-        frames = np.asarray(T.convert_fidelity(frames_u8, src_f, sf.fidelity,
-                                               self.spec))
+        with _span("encode.convert", fidelity=sf.fidelity.name(),
+                   frames=len(frames_u8)):
+            frames = T.to_host(T.convert_fidelity(frames_u8, src_f,
+                                                  sf.fidelity, self.spec))
         if sf.coding.bypass:
             return codec.encode_raw(frames)
         return codec.encode_segment(
@@ -392,7 +394,7 @@ class VideoStore:
         """Storage-grid frames -> consumption fidelity (crop + resize)."""
         sf = self.formats[sf_id]
         with _span("convert", sf=sf_id, cf=cf.name(), frames=len(frames)):
-            return np.asarray(
+            return T.to_host(
                 T.spatial_convert(frames, sf.fidelity, cf, self.spec))
 
     def has_segment(self, stream: str, seg: int, sf_id: str) -> bool:

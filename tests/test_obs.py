@@ -3,10 +3,11 @@
 import json
 import threading
 
+import numpy as np
 import pytest
 
 from repro.analytics.query import QueryResult, StageStats
-from repro.core.knobs import IngestSpec
+from repro.core.knobs import FidelityOption, IngestSpec
 from repro.launch.vserve import demo_config
 from repro.obs import (DriftDetector, Histogram, MetricsRegistry,
                        Span, Tracer, merge_reports)
@@ -45,6 +46,9 @@ def test_nesting_parents_and_attrs():
     assert si.trace_id == so.trace_id
     assert si.parent_id == so.span_id
     assert so.parent_id == 0
+    # beside its own attributes a span records its thread's CPU seconds
+    assert si.attrs.pop("cpu", 0.0) >= 0.0
+    assert so.attrs.pop("cpu", 0.0) >= 0.0
     assert si.attrs == {"bytes": 42}
     assert so.attrs == {"key": "a"}
     assert si.pid == 7 and si.dur >= 0.0
@@ -370,3 +374,205 @@ def test_query_request_trace_fields_roundtrip():
     legacy = {"query": "A", "stream": "s", "segments": [0],
               "accuracy": 0.8}
     assert QueryRequest.from_wire(legacy).trace_id == 0
+
+
+# -- per-thread tallies, evictions, compiles ------------------------------------
+
+@pytest.fixture
+def traced():
+    """The process tracer, enabled and empty; restored afterwards."""
+    obstrace.TRACER.drain()
+    obstrace.enable(True)
+    try:
+        yield obstrace.TRACER
+    finally:
+        obstrace.enable(False)
+        obstrace.TRACER.drain()
+
+
+def tally(tr):
+    """This thread's running tallies in ``tr``."""
+    return dict(getattr(tr._tls, "tally", None) or {})
+
+
+def test_tallies_nest_into_parents():
+    tr = _tracer()
+    with tr.span("outer"):
+        tr.count("h2d_bytes", 10)
+        with tr.span("a"):
+            tr.count("h2d_bytes", 100)
+            sum(range(20000))
+        with tr.span("b"):
+            tr.count("h2d_bytes", 1000)
+            tr.count("d2h_bytes", 7)
+            sum(range(20000))
+    by = {s.name: s.attrs for s in tr.spans()}
+    assert by["a"]["h2d_bytes"] == 100 and "d2h_bytes" not in by["a"]
+    assert by["b"]["h2d_bytes"] == 1000 and by["b"]["d2h_bytes"] == 7
+    assert by["outer"]["h2d_bytes"] == 1110 >= (by["a"]["h2d_bytes"]
+                                                + by["b"]["h2d_bytes"])
+    assert by["outer"]["d2h_bytes"] == 7
+    assert by["outer"]["cpu"] >= by["a"]["cpu"] + by["b"]["cpu"] > 0
+    assert tally(tr) == {"h2d_bytes": 1110, "d2h_bytes": 7}
+
+
+def test_tallies_are_per_thread():
+    tr = _tracer()
+    done = threading.Event()
+
+    def other():
+        tr.count("h2d_bytes", 5)
+        done.set()
+    with tr.span("mine"):
+        th = threading.Thread(target=other)
+        th.start()
+        th.join()
+    assert done.is_set()
+    assert "h2d_bytes" not in tr.spans()[0].attrs
+    assert tally(tr) == {}
+
+
+def test_disabled_count_leaves_tallies_unchanged():
+    tr = _tracer()
+    tr.count("h2d_bytes", 3)
+    before = tally(tr)
+    tr.enabled = False
+    assert tr.span("x") is obstrace._NOOP
+    tr.count("h2d_bytes", 1 << 20)
+    tr.count("d2h_bytes", 1 << 20)
+    assert tally(tr) == before == {"h2d_bytes": 3}
+    # the module helper: one attribute read, nothing counted
+    assert obstrace.TRACER.enabled is False
+    glob = tally(obstrace.TRACER)
+    assert obstrace.span("y") is obstrace._NOOP
+    obstrace.count("h2d_bytes", 99)
+    assert tally(obstrace.TRACER) == glob
+
+
+def test_ring_counts_evictions():
+    tr = _tracer(capacity=8)
+    for i in range(50):
+        with tr.span(f"s{i}"):
+            pass
+    assert tr.dropped == 42
+    tr.absorb([Span("r", 1, 2, 0, 0.0, 1.0, 1, 1, {}).to_wire()] * 3)
+    assert tr.dropped == 45 and len(tr.spans()) == 8
+
+
+def test_disable_reports_evictions(traced, caplog):
+    for _ in range(traced.capacity + 5):
+        with obstrace.span("flood"):
+            pass
+    with caplog.at_level("WARNING", logger=obstrace.__name__):
+        obstrace.enable(False)
+    assert "evicted 5 spans" in caplog.text
+
+
+def test_spans_carry_native_thread_id():
+    tr = _tracer()
+    with tr.span("x"):
+        pass
+    cm = tr.start_span("y")
+    tr.finish(cm)
+    assert {s.tid for s in tr.spans()} == {threading.get_native_id()}
+
+
+def test_fresh_jit_records_one_compile_span(traced):
+    import jax
+
+    import repro.codec.transform  # noqa: F401 - installs the counter
+
+    def fresh_probe(x):
+        return x * 3 + 1
+    before = obstrace.COMPILES.snapshot()
+    with obstrace.span("outer") as outer:
+        jax.jit(fresh_probe)(np.ones((7, 13), np.float32)).block_until_ready()
+    comp = [s for s in traced.spans() if s.name == "jax.compile"]
+    assert len(comp) == 1, [s.attrs for s in comp]
+    sp = comp[0]
+    assert "fresh_probe" in sp.attrs["fun"]
+    assert sp.attrs["cache"] in ("hit", "miss")
+    assert sp.parent_id == outer.span_id
+    after = obstrace.COMPILES.snapshot()
+    assert (after["compiles"] + after["cache_loads"]
+            == before["compiles"] + before["cache_loads"] + 1)
+    assert after["seconds"] - before["seconds"] == pytest.approx(sp.dur)
+    # on the tracer's clock: inside the span it ran under
+    out = [s for s in traced.spans() if s.name == "outer"][0]
+    assert out.t0 - 0.05 <= sp.t0 and sp.t0 + sp.dur <= out.t0 + out.dur + 0.05
+    ev = obstrace.COMPILES.events()[-1]
+    assert ev["fun"] == sp.attrs["fun"] and ev["s"] == sp.dur
+
+
+# -- the data path's spans and crossings at the demo grid -------------------------
+
+def _golden_ingest(tmp_path):
+    from repro.analytics.scene import generate_segment
+    from repro.ingest import IngestScheduler
+    from repro.videostore import VideoStore
+    spec = IngestSpec()
+    cfg = demo_config()
+    vs = VideoStore(str(tmp_path / "vs"), spec)
+    vs.set_formats(cfg.storage_formats())
+    sched = IngestScheduler(vs, cfg)
+    frames = [generate_segment("jackson", s, spec)[0] for s in range(3)]
+    return spec, cfg, vs, sched, frames
+
+
+def test_golden_encode_spans_and_bytes(tmp_path, traced):
+    spec, cfg, vs, sched, frames = _golden_ingest(tmp_path)
+    sched.ingest("jackson", 0, frames[0])
+    spans = traced.spans()
+    golden = [s for s in spans if s.name == "ingest.golden"]
+    assert len(golden) == 1
+    g = golden[0]
+    kids = {s.name: s for s in spans if s.parent_id == g.span_id}
+    assert {"encode.convert", "codec.transform", "codec.compress"} <= set(kids)
+    n, h, w = frames[0].shape
+    px = n * h * w
+    # uint8 frames up and down for the conversion; float32 chunks up and
+    # int16 symbols down for the transform; zstd stays on the host
+    assert kids["encode.convert"].attrs["h2d_bytes"] == px
+    assert kids["encode.convert"].attrs["d2h_bytes"] == px
+    assert kids["codec.transform"].attrs["h2d_bytes"] == 4 * px
+    assert kids["codec.transform"].attrs["d2h_bytes"] == 2 * px
+    assert "h2d_bytes" not in kids["codec.compress"].attrs
+    assert g.attrs["h2d_bytes"] == 5 * px and g.attrs["d2h_bytes"] == 3 * px
+    for s in kids.values():
+        assert 0.0 < s.attrs["cpu"] and s.t0 >= g.t0
+
+
+def test_decode_convert_detect_bytes(tmp_path, traced):
+    from repro.analytics import operators as ops
+    from repro.codec.segment import _pad_chunk_count
+    spec, cfg, vs, sched, frames = _golden_ingest(tmp_path)
+    for seg in range(3):
+        sched.ingest("jackson", seg, frames[seg])
+    golden = [sid for sid, sf in vs.formats.items() if not sf.coding.bypass][0]
+    n, h, w = frames[0].shape
+    traced.drain()
+    want = np.arange(n)
+    out, _ = vs.decode_many_for("jackson", [0, 1, 2], golden, want)
+    dec = [s for s in traced.drain() if s.name == "codec.decode"][0]
+    k = min(vs.formats[golden].coding.keyframe, n)
+    chunks = _pad_chunk_count(3 * -(-n // k))
+    # int16 symbols of every (padded) chunk up, uint8 frames down
+    assert dec.attrs["h2d_bytes"] == chunks * k * h * w * 2
+    assert dec.attrs["d2h_bytes"] == chunks * k * h * w
+    cf = FidelityOption("good", 1.0, 270, 1 / 2)
+    n2, h2, w2 = spec.resolve(cf)
+    conv = vs.convert(out[0][:n2], golden, cf)
+    sp = [s for s in traced.drain() if s.name == "convert"][0]
+    assert conv.shape == (n2, h2, w2)
+    assert sp.attrs["h2d_bytes"] == n2 * h * w * 4   # float32 frames up
+    assert sp.attrs["d2h_bytes"] == n2 * h2 * w2     # uint8 frames down
+    with obstrace.span("detect") as det:
+        ops.Diff().detect(conv, cf, spec)
+    with obstrace.span("detect.motion"):
+        ops.Motion().detect(conv, cf, spec)
+    got = {s.name: s.attrs for s in traced.drain()}
+    assert det.attrs["h2d_bytes"] == n2 * h2 * w2 * 4
+    assert got["detect"]["d2h_bytes"] == (n2 - 1) * 4   # float32 scores
+    ty, tx = ops.Motion.grid
+    assert got["detect.motion"]["h2d_bytes"] == n2 * h2 * w2 * 4
+    assert got["detect.motion"]["d2h_bytes"] == n2 * ty * tx * 4
