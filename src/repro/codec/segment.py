@@ -279,35 +279,102 @@ def segment_info(blob: bytes) -> dict:
     return header
 
 
+def _zstd_frame_size(span) -> int:
+    """Bytes of the zstd frame that starts ``span``, read from its frame
+    and block headers (RFC 8878 §3.1): a stream decoder stops quietly at
+    the end of its input, so this is how a truncated frame is told from a
+    whole one."""
+    pos = zstandard.frame_header_size(span)
+    while True:
+        if pos + 3 > len(span):
+            raise ValueError("truncated zstd frame")
+        block = int.from_bytes(span[pos:pos + 3], "little")
+        rle = (block >> 1) & 3 == 1  # an RLE block stores one byte
+        pos += 3 + (1 if rle else block >> 3)
+        if block & 1:  # the frame's last block
+            break
+    if zstandard.get_frame_parameters(span).has_checksum:
+        pos += 4
+    return pos
+
+
+def _zstd_into(span, dst: np.ndarray) -> None:
+    """Decompress the one zstd frame in ``span`` straight into ``dst``'s
+    bytes.  The frame must end where ``span`` ends and fill ``dst``
+    exactly; a short, long or corrupt span raises, as ``decompress`` and a
+    ``reshape`` to ``dst``'s shape would."""
+    if _zstd_frame_size(span) != len(span):
+        raise ValueError("zstd chunk span is not one whole frame")
+    view = memoryview(dst).cast("B")
+    with zstandard.ZstdDecompressor().stream_reader(
+            span, read_across_frames=False) as reader:
+        got = 0
+        while got < len(view):
+            n = reader.readinto(view[got:])
+            if n == 0:
+                break
+            got += n
+        if got != len(view) or reader.read(1):
+            raise ValueError(f"zstd chunk does not hold {len(view)} bytes")
+
+
+def _symbol_stack(header: dict, rows: int) -> np.ndarray:
+    """A fresh, uninitialised (rows, k_eff, hb, wb, 8, 8) int16 stack for
+    :func:`_chunk_symbols` to fill.  Never reused: the device upload of the
+    last one (on the CPU backend, an array aliasing it) may still read it."""
+    return np.empty((rows, _k_eff(header["k"], header["n"]),
+                     header["h"] // T.BLOCK, header["w"] // T.BLOCK,
+                     T.BLOCK, T.BLOCK), np.int16)
+
+
 def _chunk_symbols(header: dict, payload: bytes, chunks: np.ndarray,
-                   pad_to: int) -> tuple[np.ndarray, int]:
-    """Entropy-decode the selected ``chunks`` into a zero-padded
-    (pad_to, k, hb, wb, 8, 8) int16 stack.  Returns (symbols,
-    payload_bytes_touched): v2 touches only the selected chunks' spans, v1
-    must decompress the whole stream."""
-    n, h, w, k = header["n"], header["h"], header["w"], header["k"]
-    hb, wb = h // T.BLOCK, w // T.BLOCK
+                   out: np.ndarray) -> tuple[int, int, int]:
+    """Entropy-decode the selected ``chunks`` into rows ``0..len(chunks)``
+    of the (rows, k_eff, hb, wb, 8, 8) int16 stack ``out``, and zero every
+    byte of ``out`` no chunk writes: a short tail chunk's missing frames
+    and the rows after the chunks (DPCM is causal, so the decoder sees the
+    zeros a zero-filled stack would give).  ``out``'s prior contents are
+    never read.
+
+    v2 zstd chunks decompress in place; zlib chunks and v1 blobs (one
+    stream for the whole segment) have no in-place decoder and are
+    decompressed, then copied.  Returns (payload_bytes_touched,
+    direct_chunks, staged_bytes): v2 touches only the selected chunks'
+    spans, v1 the whole stream; ``staged_bytes`` counts what was zeroed or
+    copied into ``out`` beyond what a decompressor wrote there."""
+    n, k = header["n"], header["k"]
     ec = header.get("ec", "zstd")
-    out = np.zeros((pad_to, _k_eff(k, n), hb, wb, T.BLOCK, T.BLOCK),
-                   np.int16)
+    staged = out[len(chunks):].nbytes
+    out[len(chunks):] = 0
     if header.get("v", 1) >= 2:
         offsets = np.concatenate([[0], np.cumsum(header["spans"])])
-        touched = 0
+        buf = memoryview(payload)
+        touched = direct = 0
         for i, c in enumerate(chunks):
             c = int(c)
-            raw = _decompress(ec, payload[offsets[c]:offsets[c + 1]])
             kc = min(k, n - c * k)
-            out[i, :kc] = np.frombuffer(raw, np.int16).reshape(
-                kc, hb, wb, T.BLOCK, T.BLOCK)
+            span = buf[offsets[c]:offsets[c + 1]]
+            if ec == "zstd" and zstandard is not None:
+                _zstd_into(span, out[i, :kc])
+                direct += 1
+            else:
+                out[i, :kc] = np.frombuffer(
+                    _decompress(ec, span), np.int16).reshape(
+                    kc, *out.shape[2:])
+                staged += out[i, :kc].nbytes
+            staged += out[i, kc:].nbytes
+            out[i, kc:] = 0
             touched += int(header["spans"][c])
-        return out, touched
+        return touched, direct, staged
     sym_all = np.frombuffer(_decompress(ec, payload), np.int16).reshape(
-        n, hb, wb, T.BLOCK, T.BLOCK)
+        n, *out.shape[2:])
     for i, c in enumerate(chunks):
         start = int(c) * k
         kc = min(k, n - start)
         out[i, :kc] = sym_all[start:start + kc]
-    return out, len(payload)
+        out[i, kc:] = 0
+        staged += out[i].nbytes
+    return len(payload), 0, staged
 
 
 def _decode_cost(header: dict, header_bytes: int, payload_bytes: int,
@@ -348,9 +415,10 @@ def decode_segment_ex(blob: bytes,
     chunk_of = want // k
     chunks = np.unique(chunk_of)
     with _span("codec.entropy", chunks=len(chunks)) as esp:
-        sym, touched = _chunk_symbols(header, payload, chunks,
-                                      _pad_chunk_count(len(chunks)))
-        esp.set(bytes=touched)
+        sym = _symbol_stack(header, _pad_chunk_count(len(chunks)))
+        touched, direct, staged = _chunk_symbols(header, payload, chunks,
+                                                 sym)
+        esp.set(bytes=touched, direct_chunks=direct, staged_bytes=staged)
     with _span("codec.residuals", chunks=len(chunks), frames=len(want)):
         decoded = _run_decode(sym, header)  # (k_eff, C_padded, h, w)
     out = _scatter_rows(decoded, want, k, chunks)
@@ -417,7 +485,7 @@ def decode_many(blobs: list[bytes],
                _k_eff(header["k"], header["n"]))
         groups.setdefault(key, []).append((i, header, payload, hlen))
 
-    for (_h, _w, k, _qs, k_eff), members in groups.items():
+    for (_h, _w, k, _qs, _ke), members in groups.items():
         per_member = []
         total_chunks = 0
         for i, header, payload, hlen in members:
@@ -432,24 +500,26 @@ def decode_many(blobs: list[bytes],
                 outs[i] = np.empty((0, header["h"], header["w"]), np.uint8)
                 cost["bytes"] += hlen
             continue
-        pad = _pad_chunk_count(total_chunks)
         header0 = per_member[0][1]
-        hb, wb = header0["h"] // T.BLOCK, header0["w"] // T.BLOCK
-        sym = np.zeros((pad, k_eff, hb, wb, T.BLOCK, T.BLOCK), np.int16)
-        row = 0
+        sym = _symbol_stack(header0, _pad_chunk_count(total_chunks))
+        row = direct = staged = 0
         rowspans = []
         with _span("codec.entropy", chunks=total_chunks,
                    segments=len(per_member)) as esp:
             for i, header, payload, hlen, w_i, chunks in per_member:
-                part, touched = _chunk_symbols(header, payload, chunks,
-                                               len(chunks))
-                sym[row:row + len(chunks)] = part
+                touched, d, s = _chunk_symbols(
+                    header, payload, chunks, sym[row:row + len(chunks)])
                 rowspans.append(row)
                 row += len(chunks)
+                direct += d
+                staged += s
                 cost["bytes"] += hlen + touched
                 cost["chunks"] += len(chunks)
                 cost["frames"] += len(w_i)
-            esp.set(bytes=cost["bytes"])
+            staged += sym[row:].nbytes
+            sym[row:] = 0
+            esp.set(bytes=cost["bytes"], direct_chunks=direct,
+                    staged_bytes=staged)
         with _span("codec.residuals", chunks=total_chunks,
                    frames=cost["frames"]):
             decoded = _run_decode(sym, header0)
@@ -479,7 +549,8 @@ def decode_segment_scan(blob: bytes,
     out = np.empty((len(want), h, w), np.uint8)
     chunk_of = want // k
     chunks = np.unique(chunk_of)
-    sym_all, _ = _chunk_symbols(header, payload, chunks, len(chunks))
+    sym_all = _symbol_stack(header, len(chunks))
+    _chunk_symbols(header, payload, chunks, sym_all)
     for row, c in enumerate(chunks):
         kc = min(k, n - int(c) * k)
         # analysis: allow[jit-shape] per-chunk reference oracle, not a

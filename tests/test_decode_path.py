@@ -1,9 +1,15 @@
 """Fused batched decode path: bit-exact equivalence against the seed
 per-chunk scan decoder (property over want sets, keyframe intervals, blob
 versions, and entropy coders), v1 back-compat, chunk-granular byte
-accounting, batched multi-segment decode, Pallas-vs-jnp oracle checks, and
-jit-cache stability for tail chunks."""
+accounting, batched multi-segment decode, entropy decode straight into the
+symbol stack (against a zero-fill-and-copy reference, corrupt spans, span
+counters), Pallas-vs-jnp oracle checks, and jit-cache stability for tail
+chunks."""
 
+import struct
+import zlib
+
+import msgpack
 import numpy as np
 import pytest
 from _hyp_compat import given, settings, st
@@ -12,6 +18,15 @@ from repro.codec import segment as S
 from repro.codec import transform as T
 from repro.codec.transform import temporal_indices
 from repro.core.knobs import FidelityOption, IngestSpec
+from repro.obs import trace as obstrace
+
+try:
+    import zstandard
+except ImportError:  # the codec falls back to zlib without it
+    zstandard = None
+
+needs_zstd = pytest.mark.skipif(zstandard is None,
+                                reason="in-place decode is zstd's")
 
 
 def _frames(n=16, h=48, w=64, seed=0):
@@ -161,6 +176,224 @@ def test_retrieve_many_uses_batched_decode(tmp_path):
         one, _ = vs.retrieve("s", seg, "sf0", cf)
         assert np.array_equal(out, one)
     assert cost["chunks"] > 0 and cost["bytes"] > 0
+
+
+# ---------------------------------------------------------------------------
+# entropy decode straight into the symbol stack
+# ---------------------------------------------------------------------------
+
+_GARBAGE = 0x5A5A  # stands in for what an uninitialised stack may hold
+_FRAME_BYTES = (48 // 8) * (64 // 8) * 64 * 2  # int16 symbols of a frame
+
+
+def _inflate(coder, span):
+    if coder == "zlib":
+        return zlib.decompress(span)
+    return zstandard.ZstdDecompressor().decompress(span)
+
+
+def _staged_symbols(blob, chunks, pad_to):
+    """Test-local reference: a zero-filled stack, each chunk (v2) or the
+    whole stream (v1) decompressed into a buffer of its own, then copied."""
+    header, payload = S._parse(blob)
+    n, k = header["n"], header["k"]
+    frame = (header["h"] // 8, header["w"] // 8, 8, 8)
+    out = np.zeros((pad_to, min(k, n)) + frame, np.int16)
+    if header.get("v", 1) == 1:
+        sym = np.frombuffer(_inflate(header["ec"], payload),
+                            np.int16).reshape((n,) + frame)
+        for i, c in enumerate(chunks):
+            out[i, :min(k, n - c * k)] = sym[c * k:(c + 1) * k]
+        return out
+    offs = np.cumsum([0] + list(header["spans"]))
+    for i, c in enumerate(chunks):
+        raw = _inflate(header["ec"], payload[offs[c]:offs[c + 1]])
+        out[i, :min(k, n - c * k)] = np.frombuffer(raw, np.int16).reshape(
+            (-1,) + frame)
+    return out
+
+
+def _encode_with(n, kint, version=None, coder="zstd", seed=0):
+    f = _frames(n=n, seed=seed)
+    if coder == "zlib":
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(S, "zstandard", None)
+            return _encode(f, kint=kint, version=version)
+    return _encode(f, kint=kint, version=version)
+
+
+# (frames, keyframe interval, chunks, stack rows, blob version, coder)
+_STACK_CASES = {
+    "one_full_chunk_n_lt_k": (13, 50, [0], 1, 2, "zstd"),
+    "chunks_with_short_tail": (13, 5, [0, 1, 2], 3, 2, "zstd"),
+    "subset_of_chunks": (13, 3, [1, 4], 2, 2, "zstd"),
+    "pad_rows_and_tail": (13, 5, [1, 2], 4, 2, "zstd"),
+    "zlib": (13, 5, [0, 2], 4, 2, "zlib"),
+    "v1": (13, 5, [0, 2], 4, 1, "zstd"),
+}
+
+
+@needs_zstd
+@pytest.mark.parametrize("case", list(_STACK_CASES) + ["decode_many"])
+def test_symbol_stack_matches_staged_reference(case, monkeypatch):
+    """The stack the entropy decoder leaves is the zero-fill, decompress,
+    copy stack byte for byte, whatever the stack held before: pad rows and
+    a short tail's frames read zero, never leftover memory."""
+    if case == "decode_many":
+        blobs = [_encode_with(n, 5, seed=n) for n in (13, 10, 7)]
+        monkeypatch.setattr(
+            S, "_symbol_stack", lambda header, rows: np.full(
+                (rows, 5, 6, 8, 8, 8), _GARBAGE, np.int16))
+        seen = []
+        run = S._run_decode
+        monkeypatch.setattr(S, "_run_decode",
+                            lambda sym, header: seen.append(sym.copy())
+                            or run(sym, header))
+        outs, _ = S.decode_many(blobs)
+        chunks = [np.arange(-(-n // 5)) for n in (13, 10, 7)]
+        want = np.concatenate(
+            [_staged_symbols(b, c, len(c)) for b, c in zip(blobs, chunks)]
+            + [np.zeros((1, 5, 6, 8, 8, 8), np.int16)])   # 7 rows padded to 8
+        assert len(seen) == 1 and seen[0].tobytes() == want.tobytes()
+        for blob, out in zip(blobs, outs):
+            assert np.array_equal(out, S.decode_segment(blob))
+        return
+    n, kint, chunks, rows, version, coder = _STACK_CASES[case]
+    blob = _encode_with(n, kint, version, coder)
+    header, payload = S._parse(blob)
+    out = np.full((rows, min(kint, n), 6, 8, 8, 8), _GARBAGE, np.int16)
+    S._chunk_symbols(header, payload, np.array(chunks), out)
+    assert out.tobytes() == _staged_symbols(blob, chunks, rows).tobytes()
+
+
+def _with_chunk_span(blob, c, edit):
+    """``blob`` with chunk ``c``'s compressed span replaced by
+    ``edit(span)`` and the header's spans updated to match."""
+    header, payload = S._parse(blob)
+    offs = np.cumsum([0] + list(header["spans"]))
+    spans = [payload[offs[i]:offs[i + 1]] for i in range(len(offs) - 1)]
+    spans[c] = edit(spans[c])
+    packed = msgpack.packb(dict(header, spans=[len(s) for s in spans]))
+    return struct.pack("<I", len(packed)) + packed + b"".join(spans)
+
+
+def _deflate(raw, **kw):
+    return zstandard.ZstdCompressor(level=3, **kw).compress(raw)
+
+
+def _flip(span, at):
+    b = bytearray(span)
+    b[at] ^= 0x80
+    return bytes(b)
+
+
+_CORRUPT = {
+    "truncated_by_one": lambda s: s[:-1],
+    "truncated_to_half": lambda s: s[:len(s) // 2],
+    "byte_flipped_in_data": lambda s: _flip(s, len(s) // 2),
+    "byte_flipped_in_block_header": lambda s: _flip(
+        s, zstandard.frame_header_size(s)),
+    "frame_appended": lambda s: s + s,
+    "one_symbol_more": lambda s: _deflate(_inflate("zstd", s) + b"\0\0"),
+    "one_symbol_fewer": lambda s: _deflate(_inflate("zstd", s)[:-2]),
+    # a stream decoder stops quietly inside a frame's checksum
+    "cut_in_checksum": lambda s: _deflate(_inflate("zstd", s),
+                                          write_checksum=True)[:-1],
+}
+
+
+@needs_zstd
+@pytest.mark.parametrize("corrupt", list(_CORRUPT))
+def test_corrupt_zstd_chunk_raises(corrupt):
+    """A chunk span that is short, long or corrupt raises and returns no
+    stack.  (A whole-buffer ``decompress`` ignores a second frame after
+    the first; the in-place decoder refuses a span that is not one
+    frame.)"""
+    blob = _with_chunk_span(_encode(_frames(), kint=5), 1, _CORRUPT[corrupt])
+    header, payload = S._parse(blob)
+    with pytest.raises((ValueError, zstandard.ZstdError)):
+        S.decode_segment(blob, np.array([6]))
+    out = np.empty((1, 5, 6, 8, 8, 8), np.int16)
+    with pytest.raises((ValueError, zstandard.ZstdError)):
+        S._chunk_symbols(header, payload, np.array([1]), out)
+
+
+@needs_zstd
+def test_every_bit_flip_decodes_as_decompress_does():
+    """Over every single-bit flip of one chunk's span, decompressing in
+    place raises exactly where ``decompress`` and a reshape raise, and
+    otherwise gives the same symbols."""
+    header, payload = S._parse(_encode(_frames(), kint=5))
+    offs = np.cumsum([0] + list(header["spans"]))
+    span = payload[offs[1]:offs[2]]
+    for at in range(len(span)):
+        for bit in (0x01, 0x80):
+            bad = bytearray(span)
+            bad[at] ^= bit
+            got = want = None
+            try:
+                want = np.frombuffer(zstandard.ZstdDecompressor().decompress(
+                    bytes(bad)), np.int16).reshape(5, 6, 8, 8, 8)
+            except (ValueError, MemoryError, zstandard.ZstdError):
+                pass  # a flipped content size can ask for exabytes
+            try:
+                got = np.empty((5, 6, 8, 8, 8), np.int16)
+                S._zstd_into(bytes(bad), got)
+            except (ValueError, zstandard.ZstdError):
+                got = None
+            assert (got is None) == (want is None), (at, bit)
+            assert got is None or np.array_equal(got, want), (at, bit)
+
+
+@pytest.fixture
+def traced():
+    """The process tracer, enabled and empty; restored afterwards."""
+    obstrace.TRACER.drain()
+    obstrace.enable(True)
+    try:
+        yield obstrace.TRACER
+    finally:
+        obstrace.enable(False)
+
+
+# (blobs as (frames, keyframe interval, version, coder), direct chunks,
+# staged bytes): staged is what was zeroed or copied into the stack beyond
+# what zstd wrote straight into it
+_COUNTER_CASES = {
+    "one_full_chunk": ([(13, 50, 2, "zstd")], 1, 0),
+    # 3 chunks padded to 4 rows: the pad row and the tail's 2 frames
+    "pad_row_and_tail": ([(13, 5, 2, "zstd")], 3, (5 + 2) * _FRAME_BYTES),
+    # zlib and v1 copy every frame: the whole 4-row stack is staged
+    "zlib": ([(13, 5, 2, "zlib")], 0, 4 * 5 * _FRAME_BYTES),
+    "v1": ([(13, 5, 1, "zstd")], 0, 4 * 5 * _FRAME_BYTES),
+    # decode_many: 3 + 2 chunks padded to 8 rows, tails of 3 and 5 frames
+    "decode_many": ([(13, 5, 2, "zstd"), (10, 5, 2, "zstd")], 5,
+                    (3 * 5 + 2) * _FRAME_BYTES),
+}
+
+
+@needs_zstd
+@pytest.mark.parametrize("case", _COUNTER_CASES)
+def test_entropy_span_counts_direct_and_staged(case, traced):
+    specs, direct, staged = _COUNTER_CASES[case]
+    blobs = [_encode_with(*s) for s in specs]
+    traced.drain()
+    if len(blobs) == 1:
+        S.decode_segment(blobs[0])
+    else:
+        S.decode_many(blobs)
+    ent = [s for s in traced.drain() if s.name == "codec.entropy"]
+    assert len(ent) == 1
+    assert ent[0].attrs["direct_chunks"] == direct
+    assert ent[0].attrs["staged_bytes"] == staged
+
+
+def test_entropy_counters_record_nothing_untraced():
+    obstrace.TRACER.drain()
+    assert not obstrace.TRACER.enabled
+    S.decode_segment(_encode(_frames(), kint=5))
+    S.decode_many([_encode(_frames(), kint=5)] * 2)
+    assert obstrace.TRACER.drain() == []
 
 
 # ---------------------------------------------------------------------------
