@@ -8,6 +8,8 @@ the readers stay silent there instead of reading zero."""
 
 from __future__ import annotations
 
+import contextlib
+
 from . import tracereduce as tr
 
 COMPILE_SPAN = "jax.compile"
@@ -52,8 +54,38 @@ def cpu_pct(spans: list[dict], name: str) -> float | None:
     return 100.0 * sum(float(sp["a"].get("cpu", 0.0)) for sp in sel) / wall
 
 
+class ShardCompiles:
+    """The compile counters of a sharded run's shard processes read as one:
+    every shard's compiles and cache loads, each ``t1`` on the harness's
+    clock."""
+
+    def __init__(self, events: list[dict]):
+        self._events = sorted(events, key=lambda e: e["t1"])
+
+    def events(self) -> list[dict]:
+        return list(self._events)
+
+
+# the shards' counter while a sharded run's metrics are read (see
+# ``shard_compiles``); the harness process compiles nothing then
+_SHARDS: list[ShardCompiles] = []
+
+
+@contextlib.contextmanager
+def shard_compiles(counter: ShardCompiles):
+    """Within the block, ``compile_counter()`` is the shards' counter."""
+    _SHARDS.append(counter)
+    try:
+        yield counter
+    finally:
+        _SHARDS.remove(counter)
+
+
 def compile_counter():
-    """The program's compile counter, or None where it has none."""
+    """The program's compile counter, or None where it has none; while a
+    sharded run's metrics are read, its shards' (``shard_compiles``)."""
+    if _SHARDS:
+        return _SHARDS[-1]
     from repro.obs import trace as obs
     return getattr(obs, "COMPILES", None)
 

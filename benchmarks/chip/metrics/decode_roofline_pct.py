@@ -2,7 +2,9 @@
 work could take (``roofline.least_time_s`` of the bytes it must move and
 the operations it must do, from the golden format's shapes) over the
 device time of the decode programs (``_chunk_residuals`` and
-``_residuals_scan``, by their jit names) in the traced slice."""
+``_residuals_scan``, by their jit names) in the traced slice.  Over the
+chips of a sharded cell: the least time of all their decodes / all their
+device time."""
 
 from benchmarks.chip import reference as R
 from benchmarks.chip import roofline
@@ -12,9 +14,10 @@ PROGRAMS = ("_chunk_residuals", "_residuals_scan")
 
 
 def read(run):
-    if run.trace is None:
+    traces = run.traces or run.trace
+    if not traces:
         return None
-    runs = tr.module_runs(run.trace, PROGRAMS)
+    runs = tr.module_runs(traces, PROGRAMS)
     calls = sum(1 for n, _s, _d in runs
                 if tr.module_name(n) == "jit_" + PROGRAMS[0])
     dev_s = sum(d for _n, _s, d in runs) / 1e9

@@ -8,7 +8,8 @@ Motion's tile energies, with the items each batched call returned).  The
 wrappers hold references, copy nothing, and keep frames only for a sample
 of segments chosen from the seed.  Batched operator calls are split back
 into segments by a fingerprint of each segment's frames, which the fetch
-wrapper takes of every stage-0 conversion.  A stage-0 call whose
+wrapper takes of every stage-0 conversion, and keeps for every segment
+it converts (``read``).  A stage-0 call whose
 per-frame outputs the wrappers did not see is counted (``unread``), so
 that a program that computes them elsewhere fails the check rather than
 passing it unread.
@@ -48,10 +49,34 @@ class Recorder:
         self.decodes: dict[tuple, list] = {}
         self.converts: dict[tuple, np.ndarray] = {}  # (stream, seg, cf) -> frames
         self.where: dict[bytes, tuple] = {}     # fingerprint -> (stream, seg, cf)
+        # (stream, seg, cf) -> fingerprints of every conversion fetched
+        self.read: dict[tuple, set] = {}
         self.blocks: list[tuple] = []           # (cf, fingerprint, raw, items)
         # stage-0 calls whose per-frame outputs were not seen
         self.unread = 0
         self._undo: list = []
+
+    # -- across processes ----------------------------------------------------
+    def state(self) -> dict:
+        """What the wrappers kept, to be merged into another process's
+        recorder (``merge``)."""
+        with self._mu:
+            return {"decodes": self.decodes, "converts": self.converts,
+                    "where": self.where, "read": self.read,
+                    "blocks": self.blocks, "unread": self.unread}
+
+    def merge(self, state: dict) -> None:
+        """Add another recorder's ``state``: a sharded cell's shards each
+        keep their own streams'."""
+        with self._mu:
+            self.decodes.update(state["decodes"])
+            self.converts.update(state["converts"])
+            for fp, where in state["where"].items():
+                self.where.setdefault(fp, where)
+            for key, fps in state["read"].items():
+                self.read.setdefault(key, set()).update(fps)
+            self.blocks.extend(state["blocks"])
+            self.unread += state["unread"]
 
     # -- installing ----------------------------------------------------------
     def install(self, store=None, server=None) -> "Recorder":
@@ -102,6 +127,7 @@ class Recorder:
                 fp = fingerprint(out)
                 with self._mu:
                     self.where.setdefault(fp, (stream, seg, cf))
+                    self.read.setdefault((stream, seg, cf), set()).add(fp)
                     if (stream, seg) in self.sample:
                         self.converts.setdefault((stream, seg, cf), out)
             return out, cost

@@ -41,6 +41,8 @@ def steer(monkeypatch):
     monkeypatch.setattr(H, "require_chips", lambda n: {
         "platform": "cpu", "kind": "cpu", "count": n,
         "devices": jax.devices()[:n]})
+    monkeypatch.setattr(H, "require_shard_chips", lambda devices, n: {
+        "platform": "cpu", "kind": "cpu", "count": n})
 
 
 def run(cell, seed=7, trace=False, seconds=2.0):
@@ -55,7 +57,9 @@ def test_every_named_file_is_found():
         assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
         for key in c["reduced"]:
             assert key in cfg and key in cfg["reduced"]
-        H.program_objects(cfg)
+        server = H.program_objects(cfg)[2]
+        if "deployment" in cfg:
+            H.shard_server_opts(server)
     for w in BENCH["workloads"]:
         assert T.load(w["traffic"])["kind"] in ("query", "ingest")
     for m in BENCH["end_to_end"] + BENCH["per_layer"]:
@@ -168,6 +172,26 @@ def test_stage0_called_past_the_recorder_is_refused(steer, monkeypatch):
     assert checks["uncompared"]["value"] > checks["uncompared"]["limit"]
 
 
+def test_a_segment_holding_another_segments_bytes_is_refused(steer,
+                                                            monkeypatch):
+    """cam0's segment 1 holds segment 0's bytes: every read of it converts
+    other frames than its pooled segment's."""
+    from repro.videostore import VideoStore
+    last = {}
+
+    def wrap(orig):
+        def put_segment(self, stream, seg, sf_id, blob, *a, **k):
+            prev, last[sf_id] = last.get(sf_id, blob), blob
+            if (stream, seg) == ("cam0", 1):
+                blob = prev
+            return orig(self, stream, seg, sf_id, blob, *a, **k)
+        return put_segment
+    _break(monkeypatch, VideoStore, "put_segment", wrap)
+    line, checks = run("golden720-scan")
+    assert not line["correct"]
+    assert checks["item_mismatch"]["value"] > checks["item_mismatch"]["limit"]
+
+
 def test_unwritten_derived_format_is_refused(steer, monkeypatch):
     from repro.videostore import VideoStore
 
@@ -244,7 +268,11 @@ def test_benchmark_json_keeps_its_shape():
         assert c["file"].startswith(BENCH["paths"][0] + "/")
     for w in BENCH["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] == 1 and len(w["why"]) <= 200
+        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
+        # one chip, or one chip a shard of the configuration's deployment
+        shards = H.deployment_shards(H.load_config(w["config"]), w,
+                                     T.load(w["traffic"]))
+        assert w["chips"] == (shards or 1)
     for m in BENCH["end_to_end"] + BENCH["per_layer"]:
         assert unit.match(m["unit"]) and m["better"] in ("lower", "higher")
         for cell in m.get("workloads", CELLS):

@@ -13,6 +13,12 @@ every function here:
   run, named ``jit_<function>(<fingerprint>)``.  ``anchor_ns`` is the
   trace time of an annotation the benchmark wrote at host time
   ``anchor_t``, which puts spans and device events on one clock.
+
+A sharded cell has one device trace per chip, each from the process that
+holds that chip and on that process's trace clock, with ``pid`` naming
+the spans of that process (shard *i*'s are ``i + 1``).  The functions that
+take a trace also take such a list: they sum over it, and on a single
+trace (or a list of one) give exactly what they give on that trace.
 """
 
 from __future__ import annotations
@@ -120,22 +126,49 @@ def window_ns(trace: dict) -> tuple[float, float]:
     return trace["t0_ns"], trace["t1_ns"]
 
 
-def busy_s(trace: dict) -> float:
+def as_list(traces) -> list[dict]:
+    """One trace, or a list of them (one per chip), as a list."""
+    return [traces] if isinstance(traces, dict) else list(traces)
+
+
+def _busy_ns(trace: dict) -> float:
+    """Nanoseconds of the window in which a program ran, summed over the
+    trace's chips."""
+    lo, hi = window_ns(trace)
+    return sum(covered([(s, s + d) for _n, s, d in chip["modules"]], lo, hi)
+               for chip in trace["chips"])
+
+
+def busy_s(traces) -> float:
     """Seconds of the window in which a program ran on the device,
     averaged over the chips."""
-    lo, hi = window_ns(trace)
-    if not trace["chips"]:
+    traces = as_list(traces)
+    chips = sum(len(t["chips"]) for t in traces)
+    if not chips:
         return 0.0
-    tot = sum(covered([(s, s + d) for _n, s, d in chip["modules"]], lo, hi)
-              for chip in trace["chips"])
-    return tot / len(trace["chips"]) / 1e9
+    return sum(_busy_ns(t) for t in traces) / chips / 1e9
 
 
-def idle_pct(trace: dict) -> float | None:
-    lo, hi = window_ns(trace)
-    if hi <= lo or not trace["chips"]:
+def slice_s(traces) -> float:
+    """The traced window's length in seconds, averaged over the chips."""
+    traces = as_list(traces)
+    chips = sum(len(t["chips"]) for t in traces)
+    if not chips:
+        return 0.0
+    return sum(len(t["chips"]) * (t["t1_ns"] - t["t0_ns"])
+               for t in traces) / chips / 1e9
+
+
+def idle_pct(traces) -> float | None:
+    """1 - busy / window in percent: over several chips, the busy time of
+    all of them over the sum of their windows."""
+    traces = as_list(traces)
+    if any(hi <= lo for lo, hi in map(window_ns, traces)) or not any(
+            t["chips"] for t in traces):
         return None
-    return 100.0 * (1.0 - busy_s(trace) * 1e9 / (hi - lo))
+    busy = sum(len(t["chips"]) * busy_s(t) for t in traces)
+    window = sum(len(t["chips"]) * (t["t1_ns"] - t["t0_ns"]) for t in traces)
+    return 100.0 * (1.0 - busy * 1e9 / window)
 
 
 def module_name(name: str) -> str:
@@ -143,36 +176,61 @@ def module_name(name: str) -> str:
     return re.sub(r"\(\d+\)$", "", name)
 
 
-def module_runs(trace: dict, patterns: tuple[str, ...]) -> list[tuple]:
+def module_runs(traces, patterns: tuple[str, ...]) -> list[tuple]:
     """``(name, start_ns, dur_ns)`` of the program runs, on every chip,
     that start inside the window and whose name is ``jit_<p>`` for a
     pattern ``p``."""
-    lo, hi = window_ns(trace)
     want = {f"jit_{p}" for p in patterns}
-    return [(n, s, d) for chip in trace["chips"]
-            for n, s, d in chip["modules"]
-            if lo <= s < hi and module_name(n) in want]
+    out = []
+    for trace in as_list(traces):
+        lo, hi = window_ns(trace)
+        out += [(n, s, d) for chip in trace["chips"]
+                for n, s, d in chip["modules"]
+                if lo <= s < hi and module_name(n) in want]
+    return out
 
 
-def top_modules(trace: dict, k: int = 10) -> list[list]:
+def top_modules(traces, k: int = 10) -> list[list]:
     """The ``k`` programs that took the most device time in the window,
     ``[[name, seconds], ...]``, summed over chips."""
-    lo, hi = window_ns(trace)
     agg: dict[str, float] = {}
-    for chip in trace["chips"]:
-        for n, s, d in chip["modules"]:
-            part = max(0.0, min(s + d, hi) - max(s, lo))
-            if part > 0:
-                agg[module_name(n)] = agg.get(module_name(n), 0.0) + part
+    for trace in as_list(traces):
+        lo, hi = window_ns(trace)
+        for chip in trace["chips"]:
+            for n, s, d in chip["modules"]:
+                part = max(0.0, min(s + d, hi) - max(s, lo))
+                if part > 0:
+                    agg[module_name(n)] = agg.get(module_name(n), 0.0) + part
     top = sorted(agg.items(), key=lambda kv: -kv[1])[:k]
     return [[n, v / 1e9] for n, v in top]
 
 
-def idle_gaps(trace: dict, spans: list[dict], k: int = 10) -> list[list]:
-    """The ``k`` longest idle gaps of the first chip in the window, each
-    named by what the host was doing at its middle: the innermost span
-    open then (the one that started last), else ``"no span"``.
-    ``[[name, seconds], ...]``, longest first."""
+def idle_gaps(traces, spans: list[dict], k: int = 10) -> list[list]:
+    """The ``k`` longest idle gaps in the window of the first chip of each
+    trace, each named by what the host was doing at its middle: the
+    innermost span open then (the one that started last), else ``"no
+    span"``.  A trace with a ``pid`` is named from that process's spans
+    only.  ``[[name, seconds], ...]``, longest first."""
+    gaps = []
+    for trace in as_list(traces):
+        own = (spans if "pid" not in trace
+               else [sp for sp in spans if sp.get("pid") == trace["pid"]])
+        gaps += [(b - a, trace, own, a, b) for a, b in _gaps(trace)]
+    gaps = sorted(gaps, key=lambda g: -g[0])[:k]
+    out = []
+    for _len, trace, own, a, b in gaps:
+        mid_t = host_time(trace, (a + b) / 2)
+        best = None
+        for sp in own:
+            if sp["t0"] <= mid_t <= sp["t0"] + sp["d"]:
+                if best is None or sp["t0"] > best["t0"]:
+                    best = sp
+        out.append([best["n"] if best else "no span", (b - a) / 1e9])
+    return out
+
+
+def _gaps(trace: dict) -> list[tuple[float, float]]:
+    """The idle intervals of a trace's first chip inside its window."""
     lo, hi = window_ns(trace)
     if not trace["chips"]:
         return []
@@ -186,18 +244,7 @@ def idle_gaps(trace: dict, spans: list[dict], k: int = 10) -> list[list]:
             break
     if cur < hi:
         gaps.append((cur, hi))
-    gaps = sorted((g for g in gaps if g[1] > g[0]),
-                  key=lambda g: g[0] - g[1])[:k]
-    out = []
-    for a, b in gaps:
-        mid_t = host_time(trace, (a + b) / 2)
-        best = None
-        for sp in spans:
-            if sp["t0"] <= mid_t <= sp["t0"] + sp["d"]:
-                if best is None or sp["t0"] > best["t0"]:
-                    best = sp
-        out.append([best["n"] if best else "no span", (b - a) / 1e9])
-    return out
+    return [g for g in gaps if g[1] > g[0]]
 
 
 def host_time(trace: dict, t_ns: float) -> float:
