@@ -148,9 +148,11 @@ def generate_segment(stream: str, seg: int,
         has_plate = rng.random() < plate_p
         cars.append((c, ch, cw, lane_y, v, x_start, shade, digits, has_plate))
 
-    frames = np.empty((n, h, w), np.float32)
+    # rendered a frame at a time, with no float stack of the segment; the
+    # noise is drawn in frame order, which consumes the generator's stream
+    # as one (n, h, w) draw would, so a seed keeps its bytes
+    frames = np.empty((n, h, w), np.uint8)
     truths = [CarTruth(c[0], c[7], {}, {}) for c in cars]
-    noise = rng.normal(0, 2.0, (n, h, w)).astype(np.float32)
     for t in range(n):
         off = int(round(pan * t))
         frame = bg[:, off:off + w].copy()
@@ -162,6 +164,7 @@ def generate_segment(stream: str, seg: int,
                 tr.boxes[t] = box
             if pbox is not None:
                 tr.plate_boxes[t] = pbox
-        frames[t] = frame
-    frames = (frames + noise).clip(0, 255)
-    return frames.astype(np.uint8), SegmentTruth(stream, seg, truths)
+        noise = rng.normal(0, 2.0, (h, w)).astype(np.float32)
+        frames[t] = (frame.astype(np.float32) + noise).clip(0, 255).astype(
+            np.uint8)
+    return frames, SegmentTruth(stream, seg, truths)

@@ -40,6 +40,7 @@ from ..core.configure import DerivedConfig
 from ..core.consumption import Consumer, ConsumerPlan
 from ..core.erosion import ErosionPlan
 from ..core.knobs import CodingOption, FidelityOption, IngestSpec
+from ..obs import trace as obs
 
 _LEN = struct.Struct(">I")
 _ND_TAG = "__nd__"
@@ -83,8 +84,11 @@ def unpack(blob: bytes):
 # -- framing over a stream socket -------------------------------------------
 
 def send_msg(sock, obj) -> None:
+    """Send one frame; while tracing, the thread's ``wire_tx_bytes`` tally
+    counts it, length prefix included."""
     blob = pack(obj)
     sock.sendall(_LEN.pack(len(blob)) + blob)
+    obs.count("wire_tx_bytes", _LEN.size + len(blob))
 
 
 def _recv_exact(sock, n: int) -> bytes:
@@ -98,10 +102,14 @@ def _recv_exact(sock, n: int) -> bytes:
 
 
 def recv_msg(sock):
+    """Receive one frame; while tracing, the thread's ``wire_rx_bytes``
+    tally counts it, length prefix included."""
     n = _LEN.unpack(_recv_exact(sock, _LEN.size))[0]
     if n > MAX_FRAME:
         raise WireError(f"frame of {n} bytes exceeds MAX_FRAME")
-    return unpack(_recv_exact(sock, n))
+    blob = _recv_exact(sock, n)
+    obs.count("wire_rx_bytes", _LEN.size + n)
+    return unpack(blob)
 
 
 # -- IngestSpec --------------------------------------------------------------

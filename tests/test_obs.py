@@ -449,6 +449,32 @@ def test_disabled_count_leaves_tallies_unchanged():
     assert tally(obstrace.TRACER) == glob
 
 
+def test_wire_frames_are_counted_while_tracing(traced):
+    import socket
+
+    from repro.cluster import wire
+    a, b = socket.socketpair()
+    try:
+        msg = {"op": "query", "x": list(range(50))}
+        n = 4 + len(wire.pack(msg))
+        base = tally(traced)
+        with traced.span("rpc:query"):
+            wire.send_msg(a, msg)
+            assert wire.recv_msg(b) == msg
+        sp, = traced.spans()
+        assert sp.attrs["wire_tx_bytes"] == sp.attrs["wire_rx_bytes"] == n
+        traced.enable(False)
+        wire.send_msg(a, msg)
+        assert wire.recv_msg(b) == msg
+        got = tally(traced)
+        moved = {k: got[k] - base.get(k, 0) for k in got}
+        assert {k: v for k, v in moved.items() if v} == {
+            "wire_tx_bytes": n, "wire_rx_bytes": n}
+    finally:
+        a.close()
+        b.close()
+
+
 def test_ring_counts_evictions():
     tr = _tracer(capacity=8)
     for i in range(50):
